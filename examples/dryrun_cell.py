@@ -16,10 +16,12 @@ import sys        # noqa: E402
 
 sys.path.insert(0, "src")
 
+from repro.launch import compile_cache     # noqa: E402
 from repro.launch.dryrun import run_cell    # noqa: E402
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-moe-a2.7b")
     ap.add_argument("--shape", default="train_4k")

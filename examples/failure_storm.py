@@ -21,9 +21,11 @@ from repro.core.campaign import drive_churn_trace, generate_churn_trace
 from repro.core.controller import Controller
 from repro.core.engine import PipelineEngine
 from repro.core.sandbox import CommHooks
+from repro.launch import compile_cache
 
 
 def main() -> None:
+    compile_cache.enable()
     cfg = tiny_gpt(layers=4, d=128, heads=4, vocab=512)
     cluster = Cluster(16, device_capacity=32 * 2 ** 30)
     clock = SimClock()

@@ -26,6 +26,7 @@ from repro.configs.gpt import tiny_gpt
 from repro.core.controller import Controller
 from repro.core.engine import PipelineEngine
 from repro.core.sandbox import CommHooks
+from repro.launch import compile_cache
 
 
 def build(cfg, dp, pp, batch, seq, standby=1):
@@ -39,6 +40,7 @@ def build(cfg, dp, pp, batch, seq, standby=1):
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--small", action="store_true",

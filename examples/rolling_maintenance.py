@@ -24,9 +24,11 @@ from repro.configs.gpt import tiny_gpt
 from repro.core.controller import Controller
 from repro.core.engine import PipelineEngine
 from repro.core.sandbox import CommHooks
+from repro.launch import compile_cache
 
 
 def main() -> None:
+    compile_cache.enable()
     cfg = tiny_gpt(layers=2, d=128, heads=4, vocab=512)
     cluster = Cluster(16, device_capacity=32 * 2 ** 30)
     clock = SimClock()

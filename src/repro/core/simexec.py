@@ -271,14 +271,15 @@ class SimExecEngine(PipelineEngine):
 
     def set_state(self, mid: int, state: dict) -> None:
         # byte sizes come from the state itself, so a fresh joiner (not
-        # yet in the grid) restores without knowing its stage; other
-        # payload keys (sandbox_state, _seg_stage) survive like the
-        # real payload.update does
+        # yet in the grid) restores without knowing its stage;
+        # _seg_stage survives and sandbox_state is dropped, as in the
+        # real engine
         m = self.cluster[mid]
         m.payload["param_segs"] = sym_bytes(tree_bytes(state["params"]))
         m.payload["params"] = None
         m.payload["opt"] = sym_bytes(tree_bytes(state["opt"]))
         m.payload["step"] = np.int32(np.asarray(state["step"]))
+        m.payload.pop("sandbox_state", None)
 
     def get_state_flat(self, mid: int) -> Tuple[np.ndarray, int]:
         _, s = self.coords_of(mid)
@@ -288,6 +289,8 @@ class SimExecEngine(PipelineEngine):
 
     def set_state_flat(self, mid: int, stage: int, buf: np.ndarray,
                        step: int) -> None:
-        # dict.update preserves unrelated keys (sandbox_state), same as
-        # the real engine's targeted assignments
-        self.cluster[mid].payload.update(self._sym_payload(stage, step))
+        # targeted keys only, as in the real engine, which also drops
+        # the superseded sandbox_state
+        payload = self.cluster[mid].payload
+        payload.update(self._sym_payload(stage, step))
+        payload.pop("sandbox_state", None)

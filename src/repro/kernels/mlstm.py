@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG = -1e30
 
@@ -97,7 +96,7 @@ def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk: int = 64,
             pltpu.VMEM((1, kd), jnp.float32),      # n
             pltpu.VMEM((1, 1), jnp.float32),       # m
         ],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, log_i.reshape(bh, 1, s), log_f.reshape(bh, 1, s))

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd: int):
     di = pl.program_id(3)
@@ -58,7 +56,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, *, block_c: int = 128,
                                lambda e_, i, j, k: (e_, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

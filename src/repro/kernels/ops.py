@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-`interpret` defaults to True because this container is CPU-only; on a
-real TPU pass interpret=False (the pallas_call then lowers via Mosaic).
+`interpret` is a required keyword: True runs the kernel body in the
+Pallas interpreter (any backend), False lowers it through Mosaic for
+the TPU. No wrapper picks one from the attached device.
 """
 from __future__ import annotations
 
@@ -15,13 +16,11 @@ from repro.kernels import mlstm as _ml
 from repro.kernels import moe_gmm as _gmm
 from repro.kernels import rglru_scan as _rg
 
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
-                    interpret=not ON_TPU):
+                    interpret):
     """(B, H, S, K) attention via the tiled online-softmax kernel."""
     b, h, s, kd = q.shape
     fold = lambda t: t.reshape(b * h, t.shape[2], t.shape[3])
@@ -34,20 +33,19 @@ def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f",
                                              "block_d", "interpret"))
 def grouped_matmul(x, w, *, block_c=128, block_f=128, block_d=256,
-                   interpret=not ON_TPU):
+                   interpret):
     return _gmm.grouped_matmul(x, w, block_c=block_c, block_f=block_f,
                                block_d=block_d, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_d",
                                              "interpret"))
-def rglru_scan(a, x, *, block_s=256, block_d=512, interpret=not ON_TPU):
+def rglru_scan(a, x, *, block_s=256, block_d=512, interpret):
     return _rg.rglru_scan(a, x, block_s=block_s, block_d=block_d,
                           interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk=64,
-                    interpret=not ON_TPU):
+def mlstm_chunkwise(q, k, v, log_i, log_f, *, chunk=64, interpret):
     return _ml.mlstm_chunkwise(q, k, v, log_i, log_f, chunk=chunk,
                                interpret=interpret)

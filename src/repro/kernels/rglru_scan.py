@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _rglru_kernel(a_ref, x_ref, o_ref, h_ref, *, block_s: int):
     si = pl.program_id(2)      # sequence block: innermost, sequential
@@ -60,7 +58,7 @@ def rglru_scan(a: jax.Array, x: jax.Array, *, block_s: int = 256,
                                lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, x)

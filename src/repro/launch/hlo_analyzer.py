@@ -38,12 +38,8 @@ _OP_RE = re.compile(
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalized `compiled.cost_analysis()`: older JAX returns a list
-    of one per-device dict, newer JAX returns the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`compiled.cost_analysis()` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def _shapes(type_str: str) -> List[Tuple[str, Tuple[int, ...]]]:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import SHAPES, ShapeCfg
+from repro.launch import compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.train import checkpoint as ckpt_mod
@@ -34,6 +35,7 @@ from repro.train.optimizer import AdamCfg
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b",
                     choices=list(registry.ARCH_IDS) + ["gpt-medium"])

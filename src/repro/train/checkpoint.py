@@ -20,7 +20,11 @@ def _flatten(tree) -> Tuple[list, Any]:
 
 
 def tree_bytes(tree) -> int:
-    return sum(np.asarray(l).nbytes for l in jax.tree.leaves(tree))
+    """Bytes of every leaf, read from array metadata: a device array is
+    sized where it lives, never copied to the host to be measured.
+    Python scalars size as the numpy array they would become."""
+    return sum(l.nbytes if hasattr(l, "nbytes") else np.asarray(l).nbytes
+               for l in jax.tree.leaves(tree))
 
 
 def save(path: str, tree, step: int) -> int:

@@ -4,13 +4,3 @@ import os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # NOTE: no XLA_FLAGS here on purpose — tests run on the single real CPU
 # device; only launch/dryrun.py forces 512 host devices (per spec).
-
-# Offline fallback: hypothesis is not installable in this environment,
-# so the property tests run against the deterministic in-repo shim
-# (tests/_hypothesis_stub.py) when the real package is missing.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-    _hypothesis_stub.install()

@@ -104,8 +104,8 @@ def test_reduced_covers_every_kind_and_timing():
                        min_size=2, max_size=2))
 @settings(max_examples=8)
 def test_matrix_samples_as_dict(shape):
-    """Scenario matrices are property-samplable as config dicts (the
-    dictionaries strategy landing in the stub)."""
+    """Scenario matrices are property-samplable as config dicts (drawn
+    through hypothesis' dictionaries strategy)."""
     m = campaign.default_matrix(shape["dp"], shape["pp"])
     assert len(m) >= 20
 

@@ -121,7 +121,7 @@ def _leaf_specs(draw):
             for _ in range(n_leaves)]
 
 
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 @given(_leaf_specs())
 def test_segmented_spec_property_roundtrip(leaf_specs):
     """Property: flatten/unflatten round-trips any mixed-dtype tree,
@@ -142,7 +142,7 @@ def test_segmented_spec_property_roundtrip(leaf_specs):
                                               for s in spec.segments]
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(2, 5), st.sampled_from((jnp.float32, jnp.bfloat16)))
 def test_flat_adam_matches_per_leaf_adam(n_leaves, dtype):
     """Property: adam_update_flat on segment buckets is bitwise

@@ -1,6 +1,6 @@
 """Churn-storm fuzz harness.
 
-Fast part (property tests on the stub's shrinking strategies):
+Fast part (hypothesis property tests):
 - `compute_dp_resize_plan` shrink -> grow round-trip over randomly
   ordered rings, splice points and revert paths: membership AND the
   exact connection set are restored, both via a matching grow plan and
