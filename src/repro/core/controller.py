@@ -19,6 +19,7 @@ from repro.cluster.simclock import SimClock
 from repro.core import baselines
 from repro.core import standby as standby_mod
 from repro.core import state_sync
+from repro.core import tracing
 from repro.core import two_phase
 from repro.core.engine import (IterationInterrupt, PipelineEngine,
                                stage_role_key, stage_type)
@@ -241,9 +242,10 @@ class Controller:
         if not self.per_iteration_ckpt:
             return
         ring = self._training_mids()
-        for mid in ring:
-            self.imc.put(mid, self.engine.step_count,
-                         self.engine.get_state(mid), ring)
+        with tracing.span("tm:ckpt"):
+            for mid in ring:
+                self.imc.put(mid, self.engine.step_count,
+                             self.engine.get_state(mid), ring)
 
     def save_to_storage(self) -> None:
         for mid in self._training_mids():
@@ -526,25 +528,26 @@ class Controller:
         """Execute a migration run to COMMITTED, absorbing mid-switch
         faults through abort/rollback/resume cycles, then finalize the
         report from the downtime-lane delta and the journal."""
-        while True:
-            try:
-                run.execute()
-                break
-            except MidSwitchFault as fault:
-                self._recover_mid_switch(run, fault, pairing, affected,
-                                         xferred)
-        assert run.fault is None or run.fault.fired, \
-            f"armed FaultPoint {run.fault} never matched a step"
-        rep.downtime = self.clock.lane_total("downtime") - lanes0_dt
-        rep.resumes = run.resumes
-        rep.ckpt_fallbacks = run.ckpt_fallbacks
-        rep.journal = [e.step for e in run.journal]
-        self.last_run = run
-        self.reports.append(rep)
-        # the run is durable-committed: persist the post-switch group
-        # topology and the new epoch signature
-        self._journal_topology()
-        self._journal_epoch()
+        with tracing.span("tm:recovery", kind=rep.kind):
+            while True:
+                try:
+                    run.execute()
+                    break
+                except MidSwitchFault as fault:
+                    self._recover_mid_switch(run, fault, pairing, affected,
+                                             xferred)
+            assert run.fault is None or run.fault.fired, \
+                f"armed FaultPoint {run.fault} never matched a step"
+            rep.downtime = self.clock.lane_total("downtime") - lanes0_dt
+            rep.resumes = run.resumes
+            rep.ckpt_fallbacks = run.ckpt_fallbacks
+            rep.journal = [e.step for e in run.journal]
+            self.last_run = run
+            self.reports.append(rep)
+            # the run is durable-committed: persist the post-switch group
+            # topology and the new epoch signature
+            self._journal_topology()
+            self._journal_epoch()
 
     def _switch_step(self, run: MigrationRun, rep: MigrationReport,
                      g: CommGroup) -> Callable[[], None]:

@@ -26,6 +26,7 @@ from repro.cluster.node import Cluster, Machine, NodeStatus, Role
 from repro.cluster.simclock import SimClock
 from repro.core import flatbuf
 from repro.core import groups as groups_mod
+from repro.core import tracing
 from repro.core.sandbox import CommHooks, CommMode, Tape
 from repro.models import backbone, blocks
 from repro.train import data as data_mod
@@ -366,56 +367,55 @@ class PipelineEngine:
         engine cache (a cold machine compiling from scratch)."""
         if not fresh and stage in self._role_cache:
             return self._role_cache[stage]
-        cfg = self.cfg
-        fns = make_stage_fns(cfg, stage, self.pp)
-        B, S = self.mb_size, self.seq_len
-        tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        act = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.float32)
-        pspec = self._stage_param_spec(stage)
-        x_in = tok if stage == 0 else act
-        t0 = time.perf_counter()
-        out = {}
-        out["fwd"] = jax.jit(fns["fwd"]).lower(pspec, x_in).compile()
-        if stage == self.pp - 1:
-            out["last_bwd"] = jax.jit(fns["last_bwd"]) \
-                .lower(pspec, x_in, tok).compile()
-        else:
-            out["mid_bwd"] = jax.jit(fns["mid_bwd"]) \
-                .lower(pspec, x_in, act).compile()
+        with tracing.span("tm:compile_role", stage=stage):
+            cfg = self.cfg
+            fns = make_stage_fns(cfg, stage, self.pp)
+            B, S = self.mb_size, self.seq_len
+            tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
+            act = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.float32)
+            pspec = self._stage_param_spec(stage)
+            x_in = tok if stage == 0 else act
+            t0 = time.perf_counter()
+            out = {}
+            out["fwd"] = jax.jit(fns["fwd"]).lower(pspec, x_in).compile()
+            if stage == self.pp - 1:
+                out["last_bwd"] = jax.jit(fns["last_bwd"]) \
+                    .lower(pspec, x_in, tok).compile()
+            else:
+                out["mid_bwd"] = jax.jit(fns["mid_bwd"]) \
+                    .lower(pspec, x_in, act).compile()
 
-        navg_spec = jax.ShapeDtypeStruct((), jnp.float32)
-        if self.use_flat_buffers:
-            spec = self.flat_spec(stage)
-            seg_specs = tuple(jax.ShapeDtypeStruct((g.size,), g.dtype)
-                              for g in spec.segments)
-            # drain a replica's accumulated grad tree into its
-            # per-dtype buckets (one program; on real accelerators XLA
-            # writes the grads straight into the bucket layout)
-            out["flatten"] = jax.jit(
-                lambda t: spec.flatten(t)).lower(pspec).compile()
-            # params materialize from the buckets only at the fwd/bwd
-            # boundary (leavers ship the buckets without ever paying
-            # this)
-            out["unflatten"] = jax.jit(
-                lambda segs: spec.unflatten(segs)).lower(
+            navg_spec = jax.ShapeDtypeStruct((), jnp.float32)
+            if self.use_flat_buffers:
+                spec = self.flat_spec(stage)
+                seg_specs = tuple(jax.ShapeDtypeStruct((g.size,), g.dtype)
+                                  for g in spec.segments)
+                # drain a replica's accumulated grad tree into its
+                # per-dtype buckets (one program; on real accelerators XLA
+                # writes the grads straight into the bucket layout)
+                out["flatten"] = jax.jit(spec.flatten).lower(pspec).compile()
+                # params materialize from the buckets only at the fwd/bwd
+                # boundary (leavers ship the buckets without ever paying
+                # this)
+                out["unflatten"] = jax.jit(spec.unflatten).lower(
                     seg_specs).compile()
-            ospec = jax.eval_shape(
-                lambda p: opt_mod.init_flat_opt_state(spec, p), pspec)
-            out["update"] = jax.jit(
-                make_flat_update(spec, self.adam)).lower(
-                    seg_specs, ospec, navg_spec).compile()
-        else:
-            ospec = jax.eval_shape(opt_mod.init_opt_state, pspec)
+                ospec = jax.eval_shape(
+                    lambda p: opt_mod.init_flat_opt_state(spec, p), pspec)
+                out["update"] = jax.jit(
+                    make_flat_update(spec, self.adam)).lower(
+                        seg_specs, ospec, navg_spec).compile()
+            else:
+                ospec = jax.eval_shape(opt_mod.init_opt_state, pspec)
 
-            def upd(grads, opt, n_avg):
-                g = jax.tree.map(lambda x: x / n_avg.astype(x.dtype),
-                                 grads)
-                return opt_mod.adam_update(g, opt, self.adam,
-                                           param_dtype=None)
+                def upd(grads, opt, n_avg):
+                    g = jax.tree.map(lambda x: x / n_avg.astype(x.dtype),
+                                     grads)
+                    return opt_mod.adam_update(g, opt, self.adam,
+                                               param_dtype=None)
 
-            out["update"] = jax.jit(upd).lower(
-                pspec, ospec, navg_spec).compile()
-        dt = time.perf_counter() - t0
+                out["update"] = jax.jit(upd).lower(
+                    pspec, ospec, navg_spec).compile()
+            dt = time.perf_counter() - t0
         role = CompiledRole(out, dt)
         if not fresh:
             self._role_cache[stage] = role
@@ -508,86 +508,89 @@ class PipelineEngine:
         never of tensor values — because core/simexec.py mirrors the
         exact charge sequence tensor-free and tests pin the two
         ledgers bit-for-bit (tests/test_simexec.py)."""
-        it = self.step_count if it is None else it
-        comm = self.comm
-        comm.reset_counters()
-        losses = []
-        grads_acc: Dict[Tuple[int, int], Any] = {}
-        # compute-time charge (simulated cluster time): the critical
-        # machine is the slowest of (straggle factor x hosted-rank
-        # load) — a degraded-mode host runs its stage compute once per
-        # rank it serves, so hosting shows up as throughput, never as
-        # different math
-        load: Dict[int, int] = {}
-        for d in range(self.dp):
-            for s in range(self.pp):
-                mid = self._mid(d, s)
-                load[mid] = load.get(mid, 0) + 1
-        slow = max(self.cluster[mid].straggle_factor * n
-                   for mid, n in load.items())
-        t_comp = 3 * self._stage_flops * self.nmb * slow / \
-            (FLOPS_PER_GPU * self.cluster[self._mid(0, 0)].gpus)
-        overlap = self.use_flat_buffers
-        if not overlap:
-            self.clock.advance(t_comp, "compute", lane=lane)
-
-        for d in range(self.dp):
-            acts: Dict[Tuple[int, int], Any] = {}
-            for mb in range(self.nmb):
-                tokens = self._mb_tokens(it, d, mb)
-                x = tokens
+        with tracing.span("tm:train_iteration"):
+            it = self.step_count if it is None else it
+            comm = self.comm
+            comm.reset_counters()
+            losses = []
+            grads_acc: Dict[Tuple[int, int], Any] = {}
+            # compute-time charge (simulated cluster time): the critical
+            # machine is the slowest of (straggle factor x hosted-rank
+            # load) — a degraded-mode host runs its stage compute once per
+            # rank it serves, so hosting shows up as throughput, never as
+            # different math
+            load: Dict[int, int] = {}
+            for d in range(self.dp):
                 for s in range(self.pp):
-                    m = self.machine(d, s)
-                    fns = self.compile_role(s).fns
-                    if s > 0:
-                        x = comm.p2p_recv(stage_role_key(s), "act",
-                                          src=self._mid(d, s - 1),
-                                          dst=m.mid, value=x,
-                                          overlap=overlap)
-                    acts[(s, mb)] = x
-                    if s < self.pp - 1:
-                        y = fns["fwd"](self._stage_params(m), x)
-                        comm.p2p_send(stage_role_key(s), "act", m.mid,
-                                      self._mid(d, s + 1), y)
-                        x = y
-                # backward
-                dy = None
-                for s in reversed(range(self.pp)):
-                    m = self.machine(d, s)
-                    fns = self.compile_role(s).fns
-                    if s == self.pp - 1:
-                        loss, dp_, dx = fns["last_bwd"](
-                            self._stage_params(m), acts[(s, mb)], tokens)
-                        losses.append(float(loss))
-                    else:
-                        dy = comm.p2p_recv(stage_role_key(s), "grad",
-                                           src=self._mid(d, s + 1),
-                                           dst=m.mid, value=dy,
-                                           overlap=overlap)
-                        dp_, dx = fns["mid_bwd"](self._stage_params(m),
-                                                 acts[(s, mb)], dy)
-                    if s > 0:
-                        comm.p2p_send(stage_role_key(s), "grad", m.mid,
-                                      self._mid(d, s - 1), dx)
-                        dy = dx
-                    key = (d, s)
-                    grads_acc[key] = dp_ if key not in grads_acc else \
-                        jax.tree.map(jnp.add, grads_acc[key], dp_)
+                    mid = self._mid(d, s)
+                    load[mid] = load.get(mid, 0) + 1
+            slow = max(self.cluster[mid].straggle_factor * n
+                       for mid, n in load.items())
+            t_comp = 3 * self._stage_flops * self.nmb * slow / \
+                (FLOPS_PER_GPU * self.cluster[self._mid(0, 0)].gpus)
+            overlap = self.use_flat_buffers
+            if not overlap:
+                self.clock.advance(t_comp, "compute", lane=lane)
 
-        # DP gradient all-reduce per stage + update
-        self._phase_point("pre_reduce", it)
-        navg = jnp.asarray(float(self.dp * self.nmb), jnp.float32)
-        if self.use_flat_buffers:
-            self._flat_reduce_and_update(grads_acc, navg, it, t_comp,
-                                         lane)
-        else:
-            self._leaf_reduce_and_update(grads_acc, navg, it)
-        self._phase_point("post_reduce", it)
-        self.comm.barrier("iter")
-        self.step_count = it + 1
-        loss = float(np.mean(losses))
-        self.losses.append(loss)
-        return loss
+            for d in range(self.dp):
+                acts: Dict[Tuple[int, int], Any] = {}
+                for mb in range(self.nmb):
+                    tokens = self._mb_tokens(it, d, mb)
+                    x = tokens
+                    for s in range(self.pp):
+                        m = self.machine(d, s)
+                        fns = self.compile_role(s).fns
+                        if s > 0:
+                            x = comm.p2p_recv(stage_role_key(s), "act",
+                                              src=self._mid(d, s - 1),
+                                              dst=m.mid, value=x,
+                                              overlap=overlap)
+                        acts[(s, mb)] = x
+                        if s < self.pp - 1:
+                            y = fns["fwd"](self._stage_params(m), x)
+                            comm.p2p_send(stage_role_key(s), "act", m.mid,
+                                          self._mid(d, s + 1), y)
+                            x = y
+                    # backward
+                    dy = None
+                    for s in reversed(range(self.pp)):
+                        m = self.machine(d, s)
+                        fns = self.compile_role(s).fns
+                        if s == self.pp - 1:
+                            loss, dp_, dx = fns["last_bwd"](
+                                self._stage_params(m), acts[(s, mb)], tokens)
+                            with tracing.span("tm:loss_sync"):
+                                losses.append(float(loss))
+                        else:
+                            dy = comm.p2p_recv(stage_role_key(s), "grad",
+                                               src=self._mid(d, s + 1),
+                                               dst=m.mid, value=dy,
+                                               overlap=overlap)
+                            dp_, dx = fns["mid_bwd"](self._stage_params(m),
+                                                     acts[(s, mb)], dy)
+                        if s > 0:
+                            comm.p2p_send(stage_role_key(s), "grad", m.mid,
+                                          self._mid(d, s - 1), dx)
+                            dy = dx
+                        key = (d, s)
+                        grads_acc[key] = dp_ if key not in grads_acc else \
+                            jax.tree.map(jnp.add, grads_acc[key], dp_)
+
+            # DP gradient all-reduce per stage + update
+            self._phase_point("pre_reduce", it)
+            navg = jnp.asarray(float(self.dp * self.nmb), jnp.float32)
+            with tracing.span("tm:reduce_update"):
+                if self.use_flat_buffers:
+                    self._flat_reduce_and_update(grads_acc, navg, it, t_comp,
+                                                 lane)
+                else:
+                    self._leaf_reduce_and_update(grads_acc, navg, it)
+            self._phase_point("post_reduce", it)
+            self.comm.barrier("iter")
+            self.step_count = it + 1
+            loss = float(np.mean(losses))
+            self.losses.append(loss)
+            return loss
 
     def _flat_reduce_and_update(self, grads_acc, navg, it: int,
                                 t_comp: float, lane: str) -> None:
@@ -702,83 +705,90 @@ class PipelineEngine:
         Compiles the role's programs (REAL XLA compile, measured) and
         executes one isolated iteration fed from the tape. Returns the
         compiled role; the machine's warm_roles cache is populated."""
-        prev_mode, prev_members = self.comm.mode, self.comm.sandbox_members
-        self.comm.mode = CommMode.REPLAY
-        self.comm.sandbox_members = {machine.mid}
-        self.comm.reset_counters()
-        try:
-            role = self.compile_role(stage, fresh=fresh_compile)
-            # machine state for the shadow run: checkpoint pull or zeros
-            if state is None:
-                full = backbone.init_params(
-                    self.cfg, jax.random.PRNGKey(self.seed), tp=1,
-                    dtype=jnp.float32)
-                params = jax.tree.map(
-                    jnp.asarray,
-                    self._cast_stage_params(split_stage_params(
-                        full, stage, self.pp, self.cfg)))
-                opt = (opt_mod.init_flat_opt_state(self.flat_spec(stage),
-                                                   params)
-                       if self.use_flat_buffers
-                       else opt_mod.init_opt_state(params))
-                state = {"params": params, "opt": opt, "step": 0}
-            t0 = time.perf_counter()
-            tokens = self._mb_tokens(0, 0, 0)
-            # middle stages replay ONE fused act+grad entry when the
-            # record step coalesced the tape (first/last have only one
-            # direction recorded, so they keep the per-tag entry)
-            fused = self.comm.tape.has((role_key, "p2p", "io", 0))
-            io = (self.comm.p2p_recv(role_key, "io", src=-1,
-                                     dst=machine.mid, value=None)
-                  if fused else None)
-            if stage == 0:
-                x = tokens
-            else:
-                x = io[0] if fused else self.comm.p2p_recv(
-                    role_key, "act", src=-1, dst=machine.mid, value=None)
-            if stage == self.pp - 1:
-                _, dp_, _ = role.fns["last_bwd"](state["params"], x, tokens)
-            else:
-                y = role.fns["fwd"](state["params"], x)
-                dy = io[1] if fused else self.comm.p2p_recv(
-                    role_key, "grad", src=-1, dst=machine.mid, value=None)
-                dp_, _ = role.fns["mid_bwd"](state["params"], x, dy)
-            navg = jnp.asarray(float(self.dp * self.nmb), jnp.float32)
-            if self.use_flat_buffers:
-                # per-dtype bucket entries replayed from the tape, not
-                # per-leaf (same keys the async issue wrote)
-                buckets = role.fns["flatten"](dp_)
-                reduced = tuple(
-                    self.comm.all_reduce(role_key, "gradbucket", [b])
-                    for b in buckets)
-            else:
-                leaves = jax.tree.leaves(dp_)
-                red = [self.comm.all_reduce(role_key, f"grad{i}", [g])
-                       for i, g in enumerate(leaves)]
-                reduced = jax.tree.unflatten(jax.tree.structure(dp_), red)
-            role.fns["update"](reduced, state["opt"], navg)
-            shadow_exec = time.perf_counter() - t0
-            machine.warm_roles[role_key] = role
-            machine.payload.setdefault("sandbox_state", state)
-            self.clock.advance(self.compile_charge(role, shadow_exec),
-                               f"shadow:{role_key}", lane=lane)
-            return role
-        finally:
-            self.comm.mode = prev_mode
-            self.comm.sandbox_members = prev_members
+        with tracing.span("tm:shadow_iteration"):
+            prev_mode, prev_members = self.comm.mode, self.comm.sandbox_members
+            self.comm.mode = CommMode.REPLAY
+            self.comm.sandbox_members = {machine.mid}
+            self.comm.reset_counters()
+            try:
+                role = self.compile_role(stage, fresh=fresh_compile)
+                # machine state for the shadow run: checkpoint pull or zeros
+                if state is None:
+                    full = backbone.init_params(
+                        self.cfg, jax.random.PRNGKey(self.seed), tp=1,
+                        dtype=jnp.float32)
+                    params = jax.tree.map(
+                        jnp.asarray,
+                        self._cast_stage_params(split_stage_params(
+                            full, stage, self.pp, self.cfg)))
+                    opt = (opt_mod.init_flat_opt_state(self.flat_spec(stage),
+                                                       params)
+                           if self.use_flat_buffers
+                           else opt_mod.init_opt_state(params))
+                    state = {"params": params, "opt": opt, "step": 0}
+                t0 = time.perf_counter()
+                tokens = self._mb_tokens(0, 0, 0)
+                # middle stages replay ONE fused act+grad entry when the
+                # record step coalesced the tape (first/last have only one
+                # direction recorded, so they keep the per-tag entry)
+                fused = self.comm.tape.has((role_key, "p2p", "io", 0))
+                io = (self.comm.p2p_recv(role_key, "io", src=-1,
+                                         dst=machine.mid, value=None)
+                      if fused else None)
+                if stage == 0:
+                    x = tokens
+                else:
+                    x = io[0] if fused else self.comm.p2p_recv(
+                        role_key, "act", src=-1, dst=machine.mid, value=None)
+                if stage == self.pp - 1:
+                    _, dp_, _ = role.fns["last_bwd"](state["params"], x,
+                                                     tokens)
+                else:
+                    y = role.fns["fwd"](state["params"], x)
+                    dy = io[1] if fused else self.comm.p2p_recv(
+                        role_key, "grad", src=-1, dst=machine.mid, value=None)
+                    dp_, _ = role.fns["mid_bwd"](state["params"], x, dy)
+                navg = jnp.asarray(float(self.dp * self.nmb), jnp.float32)
+                if self.use_flat_buffers:
+                    # per-dtype bucket entries replayed from the tape, not
+                    # per-leaf (same keys the async issue wrote)
+                    buckets = role.fns["flatten"](dp_)
+                    reduced = tuple(
+                        self.comm.all_reduce(role_key, "gradbucket", [b])
+                        for b in buckets)
+                else:
+                    leaves = jax.tree.leaves(dp_)
+                    red = [self.comm.all_reduce(role_key, f"grad{i}", [g])
+                           for i, g in enumerate(leaves)]
+                    reduced = jax.tree.unflatten(jax.tree.structure(dp_), red)
+                role.fns["update"](reduced, state["opt"], navg)
+                shadow_exec = time.perf_counter() - t0
+                machine.warm_roles[role_key] = role
+                machine.payload.setdefault("sandbox_state", state)
+                self.clock.advance(self.compile_charge(role, shadow_exec),
+                                   f"shadow:{role_key}", lane=lane)
+                return role
+            finally:
+                self.comm.mode = prev_mode
+                self.comm.sandbox_members = prev_members
 
     # ------------------------------------------------------- state moves
     def get_state(self, mid: int) -> dict:
         m = self.cluster[mid]
-        if self.use_flat_buffers:
-            self._stage_params(m)               # materialize if lazy
-        return jax.tree.map(np.asarray,
-                            {k: m.payload[k]
-                             for k in ("params", "opt", "step")})
+        with tracing.span("tm:get_state"):
+            if self.use_flat_buffers:
+                self._stage_params(m)           # materialize if lazy
+            state = jax.tree.map(np.asarray,
+                                 {k: m.payload[k]
+                                  for k in ("params", "opt", "step")})
+            tracing.count("bytes.d2h", tree_bytes(state))
+        return state
 
     def set_state(self, mid: int, state: dict) -> None:
         m = self.cluster[mid]
-        m.payload.update(jax.tree.map(jnp.asarray, state))
+        with tracing.span("tm:set_state"):
+            m.payload.update(jax.tree.map(jnp.asarray, state))
+            tracing.count("bytes.h2d", tree_bytes(state))
         # the real state supersedes the shadow iteration's warm-up
         # state (nothing reads it again); on one device every machine
         # shares its memory, so holding both would cost a stage copy
@@ -829,29 +839,35 @@ class PipelineEngine:
         unflattened on the leaver."""
         d, s = self.coords_of(mid)
         m = self.cluster[mid]
-        if self.use_flat_buffers:
-            segs = m.payload.get("param_segs")
-            if segs is None:                    # tree-form restore
-                segs = self.flat_spec(s).flatten(m.payload["params"])
-            buf = self.state_spec(s).pack(
-                {"param_segs": tuple(segs), "opt": m.payload["opt"]})
-        else:
-            buf = self.state_spec(s).pack({"params": m.payload["params"],
-                                           "opt": m.payload["opt"]})
+        with tracing.span("tm:get_state_flat"):
+            if self.use_flat_buffers:
+                segs = m.payload.get("param_segs")
+                if segs is None:                # tree-form restore
+                    segs = self.flat_spec(s).flatten(m.payload["params"])
+                buf = self.state_spec(s).pack(
+                    {"param_segs": tuple(segs), "opt": m.payload["opt"]})
+            else:
+                buf = self.state_spec(s).pack(
+                    {"params": m.payload["params"],
+                     "opt": m.payload["opt"]})
+            tracing.count("bytes.d2h", buf.nbytes)
         return buf, int(m.payload["step"])
 
     def set_state_flat(self, mid: int, stage: int, buf: np.ndarray,
                        step: int) -> None:
-        tree = self.state_spec(stage).unpack(buf)
         m = self.cluster[mid]
-        if self.use_flat_buffers:
-            m.payload["param_segs"] = tuple(
-                jnp.asarray(b) for b in tree["param_segs"])
-            m.payload["params"] = None          # lazy: next fwd/bwd
-            m.payload["_seg_stage"] = stage
-        else:
-            m.payload["params"] = jax.tree.map(jnp.asarray, tree["params"])
-        m.payload["opt"] = jax.tree.map(jnp.asarray, tree["opt"])
+        with tracing.span("tm:set_state_flat"):
+            tree = self.state_spec(stage).unpack(buf)
+            if self.use_flat_buffers:
+                m.payload["param_segs"] = tuple(
+                    jnp.asarray(b) for b in tree["param_segs"])
+                m.payload["params"] = None      # lazy: next fwd/bwd
+                m.payload["_seg_stage"] = stage
+            else:
+                m.payload["params"] = jax.tree.map(jnp.asarray,
+                                                   tree["params"])
+            m.payload["opt"] = jax.tree.map(jnp.asarray, tree["opt"])
+            tracing.count("bytes.h2d", buf.nbytes)
         m.payload["step"] = step
         m.payload.pop("sandbox_state", None)    # as in set_state
 

@@ -36,6 +36,8 @@ import enum
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
+from repro.core import tracing
+
 
 class MigState(enum.Enum):
     IDLE = "idle"
@@ -234,7 +236,8 @@ class MigrationRun:
                 if st.state_after is not None:
                     self.state = st.state_after
                 continue
-            st.fn()
+            with tracing.span(f"tm:step:{st.kind}", step=st.name):
+                st.fn()
             self.exec_counts[st.name] = self.exec_counts.get(st.name, 0) + 1
             self.done.add(st.name)
             if st.state_after is not None:

@@ -13,6 +13,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 
 def _flatten(tree) -> Tuple[list, Any]:
     leaves, treedef = jax.tree.flatten(tree)
@@ -62,7 +64,8 @@ class InMemoryCheckpoint:
         self._replica: Dict[int, Tuple[int, int, Any]] = {}
 
     def put(self, node: int, step: int, state, ring: list) -> None:
-        host = jax.tree.map(np.asarray, state)
+        with tracing.span("tm:imc_put"):
+            host = jax.tree.map(np.asarray, state)
         self._own[node] = (step, host)
         if len(ring) > 1:
             holder = ring[(ring.index(node) + 1) % len(ring)]

@@ -15,6 +15,11 @@ from hypothesis import strategies as st
 
 from repro.core import campaign
 
+# the engine here charges the SimClock the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so the stage programs that each
+# fresh controller compiles again are loaded from a cache instead
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 KINDS = {"expected", "failure", "gpu_degrade", "straggler", "rebalance",
          "standby_loss", "controller_crash", "notice_drain",
          "churn_storm"}

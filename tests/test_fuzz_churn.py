@@ -32,6 +32,11 @@ from repro.core import campaign
 from repro.core.groups import (CommGroup, GroupState, apply_delta,
                                compute_dp_resize_plan, revert_delta)
 
+# the engine here charges the SimClock the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so the stage programs that each
+# fresh controller compiles again are loaded from a cache instead
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 FUZZ_CFG = campaign.CampaignCfg(
     layers=2, d_model=32, heads=2, vocab=64, global_batch=4,
     seq_len=16, micro_batches=1, warmup_iters=1, total_iters=4)

@@ -47,6 +47,12 @@ PRE_XFER_KINDS = {"prepare", "warmup", "barrier", "xfer"}
 # is the only way a draw kills the departing machine
 ROLE_POOL = ("d0s0", "d1s0", "d1s1", "standby", "joiner", "leaver")
 
+# every draw builds fresh controllers that compile the same tiny stage
+# programs again; the SimClock charges the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so loading them from the cache
+# changes no assertion
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 
 @pytest.fixture(scope="module")
 def reference():

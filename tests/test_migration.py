@@ -17,6 +17,11 @@ from repro.core.groups import (CommGroup, GroupState, apply_delta,
 from repro.core.migration import (FaultPoint, MidSwitchFault, MigState,
                                   MigrationRun, Step)
 
+# the engine here charges the SimClock the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so the stage programs that each
+# fresh controller compiles again are loaded from a cache instead
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 
 # ------------------------------------------------ fast: run mechanics
 def _run_with(steps, fault=None):

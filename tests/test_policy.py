@@ -32,6 +32,11 @@ from repro.core.campaign import (CampaignCfg, Scenario, build_controller,
 from repro.core.migration import ControllerCrash, CrashPoint, MigState
 from repro.core.policy import (KNOWN_POLICIES, PolicyEngine, Telemetry)
 
+# the engine here charges the SimClock the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so the stage programs that each
+# fresh controller compiles again are loaded from a cache instead
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 

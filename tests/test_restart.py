@@ -14,6 +14,11 @@ from repro.core.campaign import CampaignCfg, build_controller
 from repro.core.journal import RECORD_TYPES
 from repro.core.migration import ControllerCrash, CrashPoint, MigState
 
+# the engine here charges the SimClock the modeled compile constant
+# (CampaignCfg.sim_compile_seconds), so the stage programs that each
+# fresh controller compiles again are loaded from a cache instead
+pytestmark = pytest.mark.usefixtures("persistent_compile_cache")
+
 CFG = CampaignCfg(warmup_iters=1, total_iters=4)
 
 
