@@ -199,6 +199,7 @@ class PipelineEngine:
         self._state_specs: Dict[int, flatbuf.ByteSpec] = {}
         self._grad_bytes: Dict[int, int] = {}
         self._bucket_reduce: Dict[int, Any] = {}
+        self._param_rebuild: Dict[int, Any] = {}
         # stage -> (bucket tuple, materialized params): DP replicas
         # share the broadcast buckets, so they share the unflatten too
         self._mat_cache: Dict[int, Tuple[Any, Any]] = {}
@@ -774,29 +775,63 @@ class PipelineEngine:
 
     # ------------------------------------------------------- state moves
     def get_state(self, mid: int) -> dict:
+        """A host snapshot of a machine's train state, as the in-memory
+        checkpoint and storage keep it. Flat path: the flat optimizer
+        state (`opt`: m, v, the fp32 master weights, Adam's step), the
+        step and the stage, and no params: `set_state` rebuilds them
+        from the master weights. Per-leaf path: params, opt and step
+        as trees."""
         m = self.cluster[mid]
         with tracing.span("tm:get_state"):
             if self.use_flat_buffers:
-                self._stage_params(m)           # materialize if lazy
-            state = jax.tree.map(np.asarray,
-                                 {k: m.payload[k]
-                                  for k in ("params", "opt", "step")})
+                state = {"opt": m.payload["opt"], "step": m.payload["step"],
+                         "stage": m.payload["_seg_stage"]}
+            else:
+                state = {k: m.payload[k] for k in ("params", "opt", "step")}
+            state = jax.device_get(state)
             tracing.count("bytes.d2h", tree_bytes(state))
         return state
 
+    def param_rebuild_fn(self, stage: int):
+        """A stage's params in tree form from its flat master vector,
+        by the update's own slice and cast (`params_from_master`), so
+        bitwise the params the update made. Jitted once a stage,
+        outside compile_role, which re-lowers every role program in
+        each recovery."""
+        if stage not in self._param_rebuild:
+            spec = self.flat_spec(stage)
+
+            def rebuild_params(master):
+                return spec.unflatten(
+                    opt_mod.params_from_master(spec, master))
+
+            self._param_rebuild[stage] = jax.jit(rebuild_params)
+        return self._param_rebuild[stage]
+
     def set_state(self, mid: int, state: dict) -> None:
+        """Restore a `get_state` snapshot onto `mid`, which may be a
+        joiner outside the grid. A flat snapshot's params are rebuilt
+        on the device from its master weights, in tree form, as a
+        per-leaf snapshot brings them: the machine's own buckets would
+        not be its DP peer's, so its next forward pass would unflatten
+        them into a second copy."""
         m = self.cluster[mid]
         with tracing.span("tm:set_state"):
-            m.payload.update(jax.tree.map(jnp.asarray, state))
+            if "params" in state:
+                m.payload.update(jax.tree.map(jnp.asarray, state))
+            else:
+                s = int(state["stage"])
+                opt = jax.device_put(state["opt"])
+                m.payload.update(
+                    params=self.param_rebuild_fn(s)(opt["master"]),
+                    param_segs=None, _seg_stage=s, opt=opt,
+                    step=jnp.asarray(state["step"]))
+                tracing.count("ckpt.params_rebuilt", 1)
             tracing.count("bytes.h2d", tree_bytes(state))
         # the real state supersedes the shadow iteration's warm-up
         # state (nothing reads it again); on one device every machine
         # shares its memory, so holding both would cost a stage copy
         m.payload.pop("sandbox_state", None)
-        if self.use_flat_buffers:
-            # params arrived in tree form; the stale buckets are
-            # rebuilt on demand (get_state_flat / the next update)
-            m.payload["param_segs"] = None
 
     def opt_state_tree(self, d: int, s: int) -> dict:
         """Optimizer state in per-leaf tree form (flat vectors are

@@ -259,24 +259,28 @@ class SimExecEngine(PipelineEngine):
 
     # ------------------------------------------------------- state moves
     def get_state(self, mid: int) -> dict:
-        # the step passes through as stored: a python int normally
-        # (8-byte leaf under np.asarray, like real-exec), an int32
-        # scalar after a set_state restore (real set_state's
-        # jnp.asarray downcasts it — 4-byte leaf) — keeping re-saved
-        # checkpoint byte counts identical between modes
+        # like the real flat snapshot: the flat optimizer state, the
+        # step and the stage, no params. The step passes through as
+        # stored: a python int normally (8-byte leaf under np.asarray,
+        # like real-exec), an int32 scalar after a set_state restore
+        # (real set_state's jnp.asarray downcasts it — 4-byte leaf) —
+        # keeping re-saved checkpoint byte counts identical between
+        # modes
         m = self.cluster[mid]
-        return {"params": sym_bytes(m.payload["param_segs"].nbytes),
-                "opt": sym_bytes(np.asarray(m.payload["opt"]).nbytes),
-                "step": m.payload["step"]}
+        return {"opt": sym_bytes(np.asarray(m.payload["opt"]).nbytes),
+                "step": m.payload["step"],
+                "stage": m.payload["_seg_stage"]}
 
     def set_state(self, mid: int, state: dict) -> None:
-        # byte sizes come from the state itself, so a fresh joiner (not
-        # yet in the grid) restores without knowing its stage;
-        # _seg_stage survives and sandbox_state is dropped, as in the
-        # real engine
+        # the snapshot names the stage, so a fresh joiner (not yet in
+        # the grid) gets that stage's params, which the real engine
+        # rebuilds from the master weights; sandbox_state is dropped,
+        # as in the real engine
         m = self.cluster[mid]
-        m.payload["param_segs"] = sym_bytes(tree_bytes(state["params"]))
+        s = int(state["stage"])
+        m.payload["param_segs"] = sym_bytes(self._param_bytes(s))
         m.payload["params"] = None
+        m.payload["_seg_stage"] = s
         m.payload["opt"] = sym_bytes(tree_bytes(state["opt"]))
         m.payload["step"] = np.int32(np.asarray(state["step"]))
         m.payload.pop("sandbox_state", None)

@@ -226,10 +226,13 @@ def recover_state(engine, failed: int, joiner: int,
     else:
         clock.advance(t, f"state_recover:{failed}->{joiner}", lane=lane)
     engine.set_state(joiner, state)
-    jm.device.alloc(nbytes, "train_state", clock.now)
+    # the joiner holds more than arrived: a flat snapshot carries no
+    # params, which set_state rebuilds on the device
+    jm.device.alloc(engine.state_bytes(joiner), "train_state", clock.now)
     # a general standby pre-allocated its gradient bucket during
     # preparation (off the critical path); only cold joiners alloc here
     if jm.device.tagged("grad_buffer") == 0:
-        jm.device.alloc(tree_bytes(state["params"]), "grad_buffer",
+        stage = engine.coords_of(failed)[1]
+        jm.device.alloc(engine.grad_buffer_bytes(stage), "grad_buffer",
                         clock.now)
     return TransferReport(nbytes, t, path, 0.0), step

@@ -55,6 +55,11 @@ class InMemoryCheckpoint:
     Each logical node keeps its own latest state plus a copy of its ring
     neighbour's — a failed node's state is then recoverable from the
     surviving neighbour at RDMA speed (paper refs [48, 49]).
+
+    A state is whatever `engine.get_state` snapshots. On the flat-buffer
+    path that is the flat optimizer state (Adam's moments, the fp32
+    master weights, Adam's step), the step and the stage, and no params:
+    `engine.set_state` rebuilds them bitwise from the master weights.
     """
 
     def __init__(self):

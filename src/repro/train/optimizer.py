@@ -117,6 +117,17 @@ def init_flat_opt_state(spec, params) -> dict:
     }
 
 
+def params_from_master(spec, master) -> tuple:
+    """A stage's param buckets from its flat fp32 master vector: each
+    bucket's slice of the master space, cast to the bucket's dtype.
+    The flat update makes its new buckets with it, and a restore from
+    the in-memory checkpoint (which holds no params) rebuilds them with
+    it, so the two are bitwise the same."""
+    return tuple(master[lo:hi].astype(seg.dtype)
+                 for seg, (lo, hi) in zip(spec.segments,
+                                          spec.segment_bounds()))
+
+
 def adam_update_flat(spec, grad_segs, opt_state: dict, cfg: AdamCfg):
     """AdamW over per-dtype gradient buckets; returns
     (new_param_segments, new_opt_state, stats).
@@ -147,9 +158,7 @@ def adam_update_flat(spec, grad_segs, opt_state: dict, cfg: AdamCfg):
     if cfg.weight_decay:
         delta = delta + cfg.weight_decay * master
     master = master - lr * delta
-    new_segs = tuple(master[lo:hi].astype(seg.dtype)
-                     for seg, (lo, hi) in zip(spec.segments,
-                                              spec.segment_bounds()))
+    new_segs = params_from_master(spec, master)
     new_state = {"m": m, "v": v, "master": master, "step": step}
     return new_segs, new_state, {"grad_norm": gnorm, "lr": lr}
 

@@ -133,6 +133,8 @@ def test_job_recoveries_steps_and_checkpoint_bytes():
     assert counted("bytes.d2h", out) == counted("bytes.h2d", into) > 0
     (restore,) = kids(steps["unexpected"]["recover"])
     assert restore.name == "tm:set_state" and counted("bytes.h2d", restore)
+    # the snapshot holds no params: the restore rebuilds them, once
+    assert counted("ckpt.params_rebuilt", restore) == 1
 
     # each checkpoint: one tm:get_state and one tm:imc_put a machine, and
     # the bytes counted inside it are those of the states put
