@@ -20,23 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
 from repro.cluster.node import Cluster
 from repro.cluster.simclock import SimClock
-from repro.configs.base import ArchConfig
 from repro.core.controller import Controller
 from repro.core.engine import PipelineEngine
 from repro.core.sandbox import CommHooks
 from repro.train.optimizer import AdamCfg
-
-ARCH_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
-             "head_dim", "d_ff", "vocab_size", "rope_theta", "norm_eps",
-             "tie_embeddings")
-
-
-def arch(cfg: dict) -> ArchConfig:
-    return ArchConfig(name=cfg["name"], family="dense",
-                      block_pattern=tuple(cfg["block_pattern"]),
-                      **{k: cfg[k] for k in ARCH_KEYS})
 
 
 def build(cfg: dict, seed: int) -> Controller:
@@ -45,10 +35,11 @@ def build(cfg: dict, seed: int) -> Controller:
     cluster = Cluster(dp * pp + 2 + standby, device_capacity=32 * 2 ** 30)
     clock = SimClock()
     engine = PipelineEngine(
-        arch(cfg), dp=dp, pp=pp, global_batch=cfg["global_batch"],
-        seq_len=cfg["seq_len"], cluster=cluster, clock=clock,
-        comm=CommHooks(clock), micro_batches=cfg["micro_batches"],
-        seed=seed, adam=AdamCfg(**cfg["optimizer"]),
+        spec.model(cfg).arch(cfg), dp=dp, pp=pp,
+        global_batch=cfg["global_batch"], seq_len=cfg["seq_len"],
+        cluster=cluster, clock=clock, comm=CommHooks(clock),
+        micro_batches=cfg["micro_batches"], seed=seed,
+        adam=AdamCfg(**cfg["optimizer"]),
         param_dtype=jnp.dtype(cfg["param_dtype"]))
     ctl = Controller(engine, standby_count=standby)
     ctl.bootstrap_job(list(range(dp * pp)))

@@ -12,10 +12,13 @@ passed, and ends when every training machine's state is ready. After
 it, device memory is read, the job is freed, and the plain reference
 follows the same first steps to decide `correct`.
 
-With `--trace 1` the window runs under the JAX profiler, the
-benchmark's host spans are written into the trace, and the per-layer
-metrics are read from it; otherwise the end-to-end metrics are
-reported. The last line of standard output is the JSON result.
+With `--trace 1` the runtime's own tracer (`repro.core.tracing`) is on
+from before the job is built, the window runs under the JAX profiler,
+the benchmark's host spans and the runtime's `tm:` spans are written
+into the trace, and the per-layer metrics are read from the trace and
+the tracer's records; otherwise the end-to-end metrics are reported and
+the runtime's tracer stays off. The last line of standard output is the
+JSON result.
 """
 from __future__ import annotations
 
@@ -26,8 +29,8 @@ import shutil
 import sys
 import time
 import traceback
-from dataclasses import dataclass
-from typing import Dict, List, Optional
+from dataclasses import dataclass, field
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 
@@ -48,6 +51,10 @@ class Run:
     memory_peak_bytes: int
     device_kind: str
     trace: Optional[trace_reduce.Trace] = None
+    # the runtime tracer's records (`tracing.records()`, `counts()`) on
+    # the clock of `window_start`; empty with `--trace 0`
+    program_spans: List[Any] = field(default_factory=list)
+    program_counts: List[Any] = field(default_factory=list)
 
     @property
     def cfg(self) -> dict:
@@ -69,6 +76,32 @@ class Run:
 
     def peaks(self) -> Dict[str, float]:
         return spec.peaks(self.device_kind)
+
+    def _in_window(self, a: float, b: float) -> bool:
+        return self.window_start <= a and b <= self.window_start \
+            + self.window_s
+
+    def program(self, names: Sequence[str], inside: Optional[str] = None
+                ) -> List[Any]:
+        """The window's program spans named in `names`, and with `inside`
+        only those with an ancestor of that name; a span inside another
+        of `names` is left out, so no time counts twice."""
+        spans, out = self.program_spans, []
+        for sp in spans:
+            if sp.name not in names or not self._in_window(sp.start,
+                                                           sp.end):
+                continue
+            up, seen = sp.parent, inside is None
+            while up >= 0 and spans[up].name not in names:
+                seen = seen or spans[up].name == inside
+                up = spans[up].parent
+            if up < 0 and seen:
+                out.append(sp)
+        return out
+
+    def program_s(self, names: Sequence[str],
+                  inside: Optional[str] = None) -> float:
+        return sum(sp.end - sp.start for sp in self.program(names, inside))
 
 
 def parse(argv: Optional[List[str]] = None) -> argparse.Namespace:
@@ -94,11 +127,26 @@ def _memory_peak() -> int:
     return int(stats.get("peak_bytes_in_use", 0))
 
 
+def _tracer(on: bool):
+    """The runtime's tracer, reset and turned on; None where `on` is
+    false or the runtime has none."""
+    if not on:
+        return None
+    try:
+        from repro.core import tracing
+    except ImportError:
+        return None
+    tracing.reset()
+    tracing.enable()
+    return tracing
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              t_start: float) -> dict:
     cfg, traffic = cell.config, cell.traffic
     spans = job.Spans(annotate=trace)
     phases = [("start", time.perf_counter())]
+    tracer = _tracer(trace)
     ctl = job.build(cfg, seed)
     phases.append(("build", time.perf_counter()))
     grads = job.first_grads(ctl, cfg["optimizer"]["b1"])
@@ -144,6 +192,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     downtimes = [r.downtime for r in ctl.reports]
     run = Run(cell, setup_s, t1 - t0, iterations, spans, t0,
               _memory_peak(), jax.devices()[0].device_kind)
+    if tracer is not None:
+        run.program_spans, run.program_counts = (tracer.records(),
+                                                 tracer.counts())
+        tracer.disable()
+        tracer.reset()
     if trace:
         run.trace = trace_reduce.load(str(trace_dir), SPANS)
     del drv, ctl

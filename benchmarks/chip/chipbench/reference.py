@@ -1,13 +1,15 @@
 """Plain reference of the benchmark's training jobs.
 
-Written from the model's equations in straightforward `jax.numpy`, with
-nothing imported from the runtime: the decoder block of the repo's GPT
-family (RMSNorm, rotary positions, causal softmax attention, SwiGLU
-MLP, untied head), next-token cross entropy, and AdamW with a linear
-warm-up and gradient clipping by each pipeline stage's norm. Weights
-are drawn from the seed with the same `jax.random` calls the model's
-initialiser makes, and tokens come from a copy of the seeded synthetic
-stream; both are made here, not taken from the program.
+Written in straightforward `jax.numpy`, with nothing imported from the
+runtime. The architecture is the configuration's model module
+(`models/<name>.py`, found by `spec.model`): its weights, drawn from the
+seed with the same `jax.random` calls the model's initialiser makes,
+its loss of one sequence, and which leaves each pipeline stage holds.
+What every architecture shares is here: next-token data from a copy of
+the seeded synthetic stream, rounding to a narrower format, the small
+ops a model may use (`_rms`, `_rope`), and AdamW with a linear warm-up
+and gradient clipping by each pipeline stage's norm. Weights and tokens
+are made here, not taken from the program.
 
 The reference follows the first `steps` optimizer steps of a job on the
 whole global batch, one sequence at a time ("blocks of rows"), with
@@ -16,9 +18,9 @@ loss; per (stage, leaf), the norm of the first step's clipped gradient;
 and Adam's master weights before the first and after the last step.
 
 `precision` selects the arithmetic: "reference" is the configuration's
-stated precision (float32 math; transformer-block weights rounded to
-`param_dtype`), and "control" is the next precision down, as the
-configuration's `control` entry names it (block weights rounded to
+stated precision (float32 math; the leaves the model stores in
+`param_dtype` rounded to it), and "control" is the next precision down,
+as the configuration's `control` entry names it (those leaves rounded to
 `stack_weights`, every matmul's operands to `matmul_inputs`, all other
 arithmetic in the `compute` dtype).
 """
@@ -32,8 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-LAYER_LEAVES = ("ln1", "attn.wq", "attn.wk", "attn.wv", "attn.wo", "ln2",
-                "mlp.w_gate", "mlp.w_up", "mlp.w_down")
+from chipbench import spec
 
 
 # ---------------------------------------------------------------- data
@@ -60,55 +61,12 @@ def stream_tokens(vocab: int, batch: int, seq: int, seed: int,
 
 
 # ------------------------------------------------------------- weights
-def init_weights(cfg: dict, seed: int) -> Dict[str, jax.Array]:
-    """float32 weights from the seed; layer leaves stacked over layers."""
-    d, v, f = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"]
-    h, kv = cfg["num_heads"], cfg["num_kv_heads"]
-    k = cfg["head_dim"] or d // h
-    L = cfg["num_layers"]
-
-    def scaled(key, *shape):
-        return jax.random.normal(key, shape) * (shape[0] ** -0.5)
-
-    def layer(key):
-        k_attn, k_mlp, _ = jax.random.split(key, 3)
-        ka = jax.random.split(k_attn, 4)
-        km = jax.random.split(k_mlp, 3)
-        return {"ln1": jnp.ones((d,)),
-                "attn.wq": scaled(ka[0], d, h, k),
-                "attn.wk": scaled(ka[1], d, kv, k),
-                "attn.wv": scaled(ka[2], d, kv, k),
-                "attn.wo": scaled(ka[3], h, k, d),
-                "ln2": jnp.ones((d,)),
-                "mlp.w_gate": scaled(km[0], d, f),
-                "mlp.w_up": scaled(km[1], d, f),
-                "mlp.w_down": scaled(km[2], f, d)}
-
-    def make(key):
-        keys = jax.random.split(key, 6)
-        layer_keys = jax.random.split(keys[1], L + 1)
-        layers = [layer(layer_keys[i]) for i in range(L)]
-        w = {n: jnp.stack([ly[n] for ly in layers]) for n in LAYER_LEAVES}
-        w["embed"] = jax.random.normal(keys[0], (v, d)) * 0.02
-        w["final_ln"] = jnp.ones((d,))
-        w["head"] = jax.random.normal(keys[2], (d, v)) * 0.02
-        return w
-
-    return _cached(("init", _key(cfg)), lambda: jax.jit(make))(
-        jax.random.PRNGKey(seed))
-
-
 def stage_slices(cfg: dict) -> List[Tuple[int, str, slice]]:
     """(stage, leaf, layer slice) of every leaf as a pipeline stage holds
-    it: each stage's layers stacked, the embedding on the first stage,
-    the final norm and head on the last."""
-    per = cfg["num_layers"] // cfg["pp"]
-    out = [(0, "embed", slice(None))]
-    for s in range(cfg["pp"]):
-        out += [(s, n, slice(s * per, (s + 1) * per)) for n in LAYER_LEAVES]
-    last = cfg["pp"] - 1
-    return out + [(last, "final_ln", slice(None)),
-                  (last, "head", slice(None))]
+    it, from the model's `stage_leaves`."""
+    return [(s, n, slice(None) if sl is None else sl)
+            for s, held in enumerate(spec.model(cfg).stage_leaves(cfg))
+            for n, sl in held.items()]
 
 
 # --------------------------------------------------------------- model
@@ -156,41 +114,6 @@ def _rope(x, theta):
                            -1).astype(x.dtype)
 
 
-def sequence_loss(w, tokens, cfg: dict, prec, operands: str = "float32"):
-    """Mean next-token cross entropy of one sequence (S,); every matmul's
-    operands are rounded to `operands` first."""
-    dt = w["embed"].dtype
-    eps, theta = cfg["norm_eps"], cfg["rope_theta"]
-    ein = lambda spec, a, b: jnp.einsum(spec, round_to(a, operands),
-                                        round_to(b, operands),
-                                        precision=prec)
-    x = w["embed"][tokens]
-    s = tokens.shape[0]
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    for i in range(cfg["num_layers"]):
-        ly = {n: w[n][i] for n in LAYER_LEAVES}
-        h = _rms(x, ly["ln1"], eps)
-        q = _rope(ein("sd,dhk->shk", h, ly["attn.wq"]), theta)
-        k = _rope(ein("sd,dhk->shk", h, ly["attn.wk"]), theta)
-        v = ein("sd,dhk->shk", h, ly["attn.wv"])
-        q = q * jnp.asarray(q.shape[-1] ** -0.5, dt)
-        logits = ein("shk,thk->hst", q, k)
-        logits = jnp.where(causal[None], logits, jnp.asarray(-1e30, dt))
-        p = jax.nn.softmax(logits, axis=-1)
-        o = ein("hst,thk->shk", p, v)
-        x = x + ein("shk,hkd->sd", o, ly["attn.wo"])
-        h2 = _rms(x, ly["ln2"], eps)
-        gate = jax.nn.silu(ein("sd,df->sf", h2, ly["mlp.w_gate"]))
-        x = x + ein("sf,fd->sd", gate * ein("sd,df->sf", h2,
-                                            ly["mlp.w_up"]),
-                    ly["mlp.w_down"])
-    x = _rms(x, w["final_ln"], eps)
-    logits = ein("sd,dv->sv", x, w["head"]).astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits[:-1], -1)
-    true = jnp.take_along_axis(logits[:-1], tokens[1:, None], -1)[:, 0]
-    return jnp.mean(lse - true)
-
-
 def _arith(cfg: dict, precision: str):
     """(format of the block weights, compute dtype, format of every
     matmul's operands, matmul precision)."""
@@ -203,15 +126,18 @@ def _arith(cfg: dict, precision: str):
 
 
 def _effective(master, cfg: dict, precision: str):
-    """The weights the forward pass sees: block weights rounded to their
-    stored precision, then everything cast to the compute dtype."""
+    """The weights the forward pass sees: the leaves kept in
+    `param_dtype` rounded to their stored precision, then everything
+    cast to the compute dtype."""
     stack_fmt, compute_dt, _, _ = _arith(cfg, precision)
-    return {n: (round_to(x, stack_fmt) if n in LAYER_LEAVES else x)
+    stored = spec.model(cfg).stored_in_param_dtype
+    return {n: (round_to(x, stack_fmt) if stored(n) else x)
             .astype(compute_dt) for n, x in master.items()}
 
 
 def make_grad_fn(cfg: dict, precision: str):
     _, _, operands, prec = _arith(cfg, precision)
+    sequence_loss = spec.model(cfg).sequence_loss
 
     def grad_fn(master, tokens):
         def loss(m):
@@ -224,26 +150,12 @@ def make_grad_fn(cfg: dict, precision: str):
 
 
 # ----------------------------------------------------------------- adam
-def _stage_leaves(cfg: dict):
-    """Per stage, {leaf: layer slice, or None for the whole leaf}."""
-    per = cfg["num_layers"] // cfg["pp"]
-    out = []
-    for s in range(cfg["pp"]):
-        m = {n: slice(s * per, (s + 1) * per) for n in LAYER_LEAVES}
-        if s == 0:
-            m["embed"] = None
-        if s == cfg["pp"] - 1:
-            m["final_ln"] = m["head"] = None
-        out.append(m)
-    return out
-
-
 def clip_by_stage(grads, cfg: dict):
     """Scale each stage's gradients by min(1, clip / that stage's norm)."""
     clip = cfg["optimizer"]["grad_clip"]
     part = lambda x, sl: x if sl is None else x[sl]
     out = {n: [] for n in grads}
-    for held in _stage_leaves(cfg):
+    for held in spec.model(cfg).stage_leaves(cfg):
         norm = jnp.sqrt(sum(jnp.sum(jnp.square(part(grads[n], sl)))
                             for n, sl in held.items()))
         fac = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-9))
@@ -297,9 +209,10 @@ def follow(cfg: dict, seed: int, steps: int, precision: str = "reference",
     step 0's clipped gradient, "master0": weights before step 0,
     "master": weights after the last step}; the arrays are float32 dicts
     on the device, layer leaves stacked over all layers."""
-    w0 = init_weights(cfg, seed)
+    w0 = spec.model(cfg).init_weights(cfg, seed)
     stack_dt = cfg["param_dtype"]
-    master0 = {n: round_to(x, stack_dt) if n in LAYER_LEAVES else x
+    stored = spec.model(cfg).stored_in_param_dtype
+    master0 = {n: round_to(x, stack_dt) if stored(n) else x
                for n, x in w0.items()}
     key, opt = _key(cfg), cfg["optimizer"]
     grad_fn = _cached(("grad", key, precision),

@@ -7,9 +7,11 @@ file of its own under `benchmarks/chip/`:
     configs/<config>.json     the configuration as it is run
     traffic/<mix>.json        the parameters of one traffic mix
     metrics/<metric>.py       the reader of one metric (`read(run)`)
+    models/<model>.py         the architecture a configuration names
 
-Nothing here lists a cell, a mix or a metric: a new one is a new file
-plus an entry in `BENCHMARK.json`.
+Nothing here lists a cell, a mix, a metric or a model: a new one is a
+new file plus an entry in `BENCHMARK.json` (a model, the `model` key of
+its configuration).
 """
 from __future__ import annotations
 
@@ -17,11 +19,14 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parents[1]          # benchmarks/chip
 ROOT = HERE.parents[1]                               # the checkout
 BENCHMARK = ROOT / "BENCHMARK.json"
+MODELS = HERE / "models"
+DEFAULT_MODEL = "gpt_block"
 
 
 def load_json(path: Path) -> dict:
@@ -79,16 +84,36 @@ def cell(name: str, bench: Optional[dict] = None) -> Cell:
                 [m for m in metrics(bench) if m.applies_to(name)])
 
 
+def _load(name: str, path: Path) -> ModuleType:
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str) -> Callable:
     """The `read(run)` function of metrics/<metric>.py."""
     path = HERE / "metrics" / f"{metric}.py"
     if not path.is_file():
         raise FileNotFoundError(f"metric {metric!r} has no reader at {path}")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return _load(f"chipbench_metric_{metric.replace('.', '_')}", path).read
+
+
+_MODELS: Dict[Path, ModuleType] = {}
+
+
+def model(cfg: dict) -> ModuleType:
+    """The module of models/<name>.py for the configuration's `model`
+    key (`gpt_block` where it has none), loaded once per process; an
+    unknown name is an error."""
+    name = cfg.get("model", DEFAULT_MODEL)
+    path = MODELS / f"{name}.py"
+    if path not in _MODELS:
+        if not path.is_file():
+            raise KeyError(f"no model {name!r}: {path} does not exist")
+        _MODELS[path] = _load(f"chipbench_model_{name.replace('.', '_')}",
+                              path)
+    return _MODELS[path]
 
 
 def peaks(device_kind: str) -> Dict[str, float]:

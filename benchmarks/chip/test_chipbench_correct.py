@@ -26,7 +26,15 @@ TINY = dict(num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
 
 
 def tiny_cell(workload: str) -> spec.Cell:
-    cell = spec.cell(workload)
+    """The workload's cell at a tiny width. A pair of configuration and
+    traffic that BENCHMARK.json does not list, `<config>.<traffic>`, is
+    built from their files, with no metrics."""
+    if workload in {w["name"] for w in spec.benchmark()["workloads"]}:
+        cell = spec.cell(workload)
+    else:
+        config, traffic = workload.rsplit(".", 1)
+        cell = spec.Cell(workload, 1, spec.config(config),
+                         spec.traffic(traffic), [])
     return spec.Cell(cell.name, cell.chips, {**cell.config, **TINY},
                      cell.traffic, cell.metrics)
 
@@ -123,7 +131,7 @@ FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,
 
 
 @pytest.mark.parametrize("workload", ["gpt-medium.steady",
-                                      "gpt-medium.churn"])
+                                      "gpt-medium.churn", "gpt-2.7b.churn"])
 def test_sound_run_is_correct(workload):
     out = _run(workload)
     assert out["correct"], out["checks"]
@@ -177,15 +185,21 @@ HAND_OFF = {"migration_half_copied": _half_copied,
             "failure_moments_lost": _moments_lost}
 
 
-@pytest.mark.parametrize("fault", sorted(HAND_OFF))
-def test_broken_hand_off_is_not_correct(fault, monkeypatch):
+@pytest.mark.parametrize("fault, workload", [
+    pytest.param(f, w, id=f if w == "gpt-medium.churn" else f"{f}-2.7b")
+    for w in ("gpt-medium.churn", "gpt-2.7b.churn")
+    for f in sorted(HAND_OFF)])
+def test_broken_hand_off_is_not_correct(fault, workload, monkeypatch):
     HAND_OFF[fault](monkeypatch)
-    out = _run("gpt-medium.churn")
+    out = _run(workload)
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("config", ["gpt-medium", "gpt-2.7b"])
-def test_lower_precision_control_is_not_correct(config):
+@pytest.mark.parametrize("config, steps", [
+    pytest.param("gpt-medium", 3, id="gpt-medium"),
+    pytest.param("gpt-2.7b", 3, id="gpt-2.7b"),
+    pytest.param("gpt-2.7b", 5, id="gpt-2.7b-5steps")])
+def test_lower_precision_control_is_not_correct(config, steps):
     cfg = {**spec.config(config), **TINY}
-    numbers = calibrate.stand_ins(cfg, SEED, 3)["control"]
+    numbers = calibrate.stand_ins(cfg, SEED, steps)["control"]
     assert not compare.judge(numbers, cfg["limits"]), numbers
